@@ -337,6 +337,9 @@ def main() -> None:
                      f"(a missing file must not pass the gate vacuously)")
         reference = load_tracker(check_path)
 
+    from repro import compile_cache
+    compile_cache.enable()
+
     from . import perf
 
     results = []

@@ -10,9 +10,12 @@ Run:  PYTHONPATH=src python examples/quickstart.py
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.core import (JobSpec, solve_grid, solve_algorithm1, pocd_of,
                         cost_of, utility, gamma, theory)
 from repro.kernels import ops
+
+compile_cache.enable()
 
 # A deadline-critical job: 10 tasks, task times ~ Pareto(t_min=10s, beta=2),
 # deadline 50s, straggler check at 3s, kill slow attempts at 8s.

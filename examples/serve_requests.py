@@ -58,10 +58,12 @@ if args.devices > 0 and "xla_force_host_platform_device_count" not in _flags:
 import jax
 import numpy as np
 
-from repro import RunConfig, simulate
+from repro import RunConfig, compile_cache, simulate
 from repro.serve import make_requests, serve_trace
 from repro.strategies import names
 from repro.workloads import list_scenarios
+
+compile_cache.enable()
 
 if args.scenario not in list_scenarios():
     ap.error(f"unknown scenario {args.scenario!r}; registered: "

@@ -9,10 +9,13 @@ Run:  PYTHONPATH=src python examples/serve_sla.py
 """
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import get_config
 from repro.models.inputs import make_batch
 from repro.serve import (Engine, HedgedScheduler, ReplicaPool, Request,
                          baseline_no_hedge)
+
+compile_cache.enable()
 
 # 1) a real engine decoding real tokens (reduced gemma2 for CPU speed)
 cfg = get_config("gemma2-2b").reduced()

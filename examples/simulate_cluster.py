@@ -139,11 +139,13 @@ if args.devices > 0 and "xla_force_host_platform_device_count" not in _flags:
 import jax
 import jax.numpy as jnp
 
-from repro import RunConfig, simulate
+from repro import RunConfig, compile_cache, simulate
 from repro.sim import generate, SimParams
 from repro.sim.metrics import class_summary
 from repro.strategies import names
 from repro.workloads import list_scenarios, make_trace, summarize, to_jobset
+
+compile_cache.enable()
 
 if args.trace or args.trace_out:
     from repro.obs import trace as obs_trace

@@ -17,6 +17,7 @@ import dataclasses
 
 import jax
 
+from repro import compile_cache
 from repro.configs import get_config
 from repro.configs.base import ArchConfig
 from repro.train import Trainer, TrainerConfig
@@ -34,6 +35,7 @@ def preset_cfg(name: str) -> ArchConfig:
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="tiny", choices=["tiny", "100m"])
     ap.add_argument("--steps", type=int, default=60)

@@ -41,6 +41,7 @@ from ..sim.runner import jobspecs_of, mean_over_reps, strategy_keys
 from ..sim.strategies import SimParams
 from ..sim.trace import JobSet, jobset_arrays, jobset_of
 from ..strategies import get, names, solve_jobs_jit
+from ..strategies.table import row_major
 from .admission import (AdmissionConfig, GovernorConfig, admit_jobs,
                         apply_governor)
 from .events import (AttemptTable, dispatch_scan, masked_dispatch,
@@ -131,7 +132,7 @@ def _replay_body(table: AttemptTable, race: bool, arrival, D,
     # _assemble's layout contract: row t*A + a is attempt a of task t, and
     # attempt 0 is the primary — so pass 1 (primaries only) runs on a T-row
     # slice; sorting the full U-row table for it would cost A x more.
-    col0 = lambda x: x.reshape(n_tasks, A)[:, 0]
+    col0 = lambda x: x[::A]
     act_prim_t = col0(table.active & table.is_primary)
     starts_t = masked_dispatch(slots, discipline, col0(arrival_u),
                                col0(sched_hold), act_prim_t,
@@ -259,8 +260,8 @@ def _narrow_table(table: AttemptTable, n_tasks: int,
     A = U // n_tasks
     if width is None or width >= A:
         return table
-    sl = lambda x: x.reshape(n_tasks, A)[:, :width].reshape(-1)
-    return AttemptTable(*(sl(x) for x in table))
+    t, a = row_major(n_tasks, width)
+    return AttemptTable(*(x[t * A + a] for x in table))
 
 
 @functools.partial(jax.jit, static_argnames=(

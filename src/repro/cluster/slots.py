@@ -25,6 +25,8 @@ from typing import NamedTuple
 import jax.numpy as jnp
 import numpy as np
 
+from ..sim.sorting import stable_argsort
+
 DISCIPLINES = ("fifo", "edf")
 
 
@@ -75,12 +77,12 @@ def dispatch_key_order(discipline: str, release, deadline_abs,
         key = release
         if inactive is not None:
             key = jnp.where(inactive, jnp.inf, key)
-        return jnp.argsort(key, stable=True)
+        return stable_argsort(key)
     if discipline == "edf":
         key = deadline_abs
         if inactive is not None:
             key = jnp.where(inactive, jnp.inf, key)
-        return jnp.lexsort((release, key))
+        return stable_argsort(key, release)
     raise ValueError(f"unknown discipline {discipline!r}; "
                      f"expected one of {DISCIPLINES}")
 

@@ -48,8 +48,10 @@ def quadrature_inputs(u, w):
 
     The fused grid-solve kernel (kernels/grid_solve.py) passes the
     quadrature vectors as kernel operands and traces the cost closures
-    under this context; values are the module constants, so results are
-    unchanged bit-for-bit.
+    under this context. It passes them shaped (K, 1, 1): the quadrature
+    axis then leads and broadcasts against the kernel's 2-D (r, job)
+    tiles with no in-kernel reshape (Mosaic has no lane -> sublane shape
+    cast). The node and weight values are the module constants.
     """
     global _GL_ACTIVE
     prev = _GL_ACTIVE
@@ -88,13 +90,21 @@ def cost_clone(r, t_min, beta, D, N, tau_kill):
 
 def _srestart_integral(r, t_min, beta, D, tau_est):
     """I(r) = int_{D-tau}^{inf} (D/(w+tau))^beta * (t_min/w)^(beta r) dw."""
-    u, gl_w = _GL_ACTIVE  # (K,) nodes; broadcast over leading param dims
-    r_, t_, b_, D_, tau_ = (jnp.asarray(x)[..., None] for x in (r, t_min, beta, D, tau_est))
+    u, gl_w = _GL_ACTIVE
+    params = (r, t_min, beta, D, tau_est)
+    if u.ndim == 1:
+        # (K,) nodes on a new trailing axis of the parameters
+        r_, t_, b_, D_, tau_ = (jnp.asarray(x)[..., None] for x in params)
+        axis = -1
+    else:
+        # kernel form: (K, 1, ..., 1) nodes lead the parameters' axes
+        r_, t_, b_, D_, tau_ = params
+        axis = 0
     Dm_ = jnp.maximum(D_ - tau_, t_)
     w_ = Dm_ / u
     f = jnp.power(D_ / (w_ + tau_), b_) * jnp.power(t_ / w_, b_ * r_)
     # dw = Dm / u^2 du
-    return jnp.sum(f * (Dm_ / (u * u)) * gl_w, axis=-1)
+    return jnp.sum(f * (Dm_ / (u * u)) * gl_w, axis=axis)
 
 
 def _srestart_cond_above(r, t_min, beta, D, tau_est, tau_kill):

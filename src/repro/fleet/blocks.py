@@ -31,6 +31,9 @@ from ..sim.trace import JobSet
 #: benign Pareto parameters for padding rows: finite draws, never read.
 _FILL = {"t_min": 1.0, "beta": 2.0, "D": 1.0}
 
+#: the per-block task axis is padded to a multiple of this
+_TASK_TILE = 1024
+
 
 class FleetBlocks(NamedTuple):
     """Block-stacked JobSet arrays; leading axis G_pad shards over "job"."""
@@ -139,7 +142,13 @@ def block_layout(jobs: JobSet, block_jobs: int, pad_blocks_to: int = 1,
 
     n_tasks = np.asarray(jobs.n_tasks, np.int64)
     counts = block_task_counts(n_tasks, B)
-    Tb = max(int(counts.max()), int(tasks_pad), 1)
+    # a whole number of 1024-element tiles: a TPU compiler turns every
+    # gather or scatter over (G, Tb) task rows into code that grows with
+    # Tb (~10 s per op at 128 x 38,480) unless Tb is tile-aligned (<1 s).
+    # Padding rows draw benign values that are masked, and a real task's
+    # draws depend only on its own index, so results do not change.
+    Tb = -(-max(int(counts.max()), int(tasks_pad), 1) // _TASK_TILE) \
+        * _TASK_TILE
 
     j = np.arange(J)
     # tasks are job-contiguous, so block g's tasks are the flat slice

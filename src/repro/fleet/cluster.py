@@ -25,7 +25,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..cluster.admission import (AdmissionConfig, GovernorConfig,
@@ -39,9 +38,10 @@ from ..obs.metrics import (capacity_metrics, combine_windows,
 from ..sim.metrics import StreamCombiner, aggregate, net_utility
 from ..sim.runner import jobspecs_of, strategy_keys
 from ..sim.trace import jobset_arrays, jobset_of
-from ..strategies import get, names, solve_jobs, solve_jobs_jit
+from ..strategies import get, names, solve_jobs_jit
 from .mesh import AXES, pad_count
-from .runner import _warn_saturated, chunk_jobset, job_columns
+from .runner import (_warn_saturated, chunk_jobset, job_columns,
+                     solve_on_mesh)
 
 
 def _cluster_exec(rep_ids, key, arrays, r_j, choice_j, admitted, *,
@@ -117,10 +117,13 @@ def _cluster_core_impl(key, rep_ids, arrays, r_j, choice_j, admitted, *,
     args = (rep_ids, key, arrays, r_j, choice_j, admitted)
     if mesh is None or mesh.devices.size == 1:
         return exec_fn(*args)
-    return shard_map(
+    # check_vma off: the slot-pool scan starts from replicated carries
+    # that the rep-sharded draws then vary; jax.shard_map's type check
+    # rejects that carry, the pre-0.8 shard_map did not
+    return jax.shard_map(
         exec_fn, mesh=mesh,
         in_specs=(P(AXES), P(), P(), P(), P(), P()),
-        out_specs=P(AXES))(*args)
+        out_specs=P(AXES), check_vma=False)(*args)
 
 
 _cluster_fleet_core = jax.jit(_cluster_core_impl, static_argnames=(
@@ -145,8 +148,8 @@ def _cluster_fused_impl(key, rep_ids, arrays, specs, admitted, *,
     governor/admission transforms stay host-side — they are numpy code
     operating on the specs/admitted inputs, not on solve outputs.
     """
-    r_j, choice_j, _, th_p, th_c, sat = solve_jobs(
-        strategy, specs, max_r + 1, backend=backend)
+    r_j, choice_j, _, th_p, th_c, sat = solve_on_mesh(
+        mesh, strategy, specs, max_r + 1, backend)
     th_c = th_c * specs.C
     out = _cluster_core_impl(
         key, rep_ids, arrays, r_j, choice_j, admitted, n_jobs=n_jobs,
@@ -159,14 +162,10 @@ def _cluster_fused_impl(key, rep_ids, arrays, specs, admitted, *,
 _CLUSTER_FUSED_STATIC = (
     "n_jobs", "strategy", "p", "slots", "discipline", "passes", "max_r",
     "oracle", "width", "mesh", "collect_metrics", "backend")
-if jax.default_backend() == "cpu":
-    # XLA:CPU does not implement buffer donation (runner.py idiom)
-    _cluster_fleet_fused = jax.jit(
-        _cluster_fused_impl, static_argnames=_CLUSTER_FUSED_STATIC)
-else:
-    _cluster_fleet_fused = jax.jit(
-        _cluster_fused_impl, static_argnames=_CLUSTER_FUSED_STATIC,
-        donate_argnums=(2, 3, 4))
+# no buffer donation (runner.py): a window's arrays and specs are the
+# chunk JobSet's own buffers, which a chaos retry reads again
+_cluster_fleet_fused = jax.jit(_cluster_fused_impl,
+                               static_argnames=_CLUSTER_FUSED_STATIC)
 
 
 def _rep_mean(tree, reps: int):

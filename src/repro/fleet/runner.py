@@ -37,7 +37,6 @@ import warnings
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..obs import trace as obs_trace
@@ -120,11 +119,30 @@ def _core_impl(key, rep_ids, blocks, r_blocks, choice_blocks, *,
         # single-device fast path: same computation, no partitioning
         return exec_fn(key, rep_ids, blocks, r_blocks, choice_blocks)
     blocks_spec = jax.tree.map(lambda _: P("job"), blocks)
-    return shard_map(
+    return jax.shard_map(
         exec_fn, mesh=mesh,
         in_specs=(P(), P("rep"), blocks_spec, P("job"), P("job")),
         out_specs=(P("rep", "job"), P("rep", "job")))(
             key, rep_ids, blocks, r_blocks, choice_blocks)
+
+
+def solve_on_mesh(mesh, strategy: str, specs, r_max: int, backend: str):
+    """Algorithm-1 solve of one chunk inside a (possibly multi-device)
+    chunk program.
+
+    XLA cannot partition a Mosaic kernel, so on a multi-device mesh the
+    solve runs whole on every device inside a replicated shard_map; the
+    outputs are per-job and identical on every device, so results stay
+    mesh-invariant.
+    """
+    solve = functools.partial(solve_jobs, strategy, r_max=r_max,
+                              backend=backend)
+    if mesh is None or mesh.devices.size == 1:
+        return solve(specs)
+    # check_vma off: a pallas_call's out_shape carries no varying-axes
+    # type, and every output here is replicated by construction
+    return jax.shard_map(solve, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)(specs)
 
 
 def _fused_impl(key, rep_ids, blocks, specs, task_job, *, strategy: str,
@@ -143,8 +161,8 @@ def _fused_impl(key, rep_ids, blocks, specs, task_job, *, strategy: str,
     consumes bit-identical r/choice blocks without r* ever leaving the
     device.
     """
-    r_j, choice_j, _, th_p, th_c, sat = solve_jobs(
-        strategy, specs, max_r + 1, backend=backend)
+    r_j, choice_j, _, th_p, th_c, sat = solve_on_mesh(
+        mesh, strategy, specs, max_r + 1, backend)
     th_c = th_c * specs.C
     pad0 = lambda x: jnp.concatenate([x, jnp.zeros((1,), x.dtype)])
     r_b = pad0(r_j)[task_job]
@@ -155,18 +173,13 @@ def _fused_impl(key, rep_ids, blocks, specs, task_job, *, strategy: str,
 
 
 _STATIC = ("strategy", "p", "max_r", "oracle", "mesh")
-if jax.default_backend() == "cpu":
-    # XLA:CPU does not implement buffer donation — donating would only
-    # log warnings per chunk, so the CPU entries skip it
-    _fleet_core = jax.jit(_core_impl, static_argnames=_STATIC)
-    _fleet_fused = jax.jit(_fused_impl,
-                           static_argnames=_STATIC + ("backend",))
-else:
-    _fleet_core = jax.jit(_core_impl, static_argnames=_STATIC,
-                          donate_argnums=(2, 3, 4))
-    _fleet_fused = jax.jit(_fused_impl,
-                           static_argnames=_STATIC + ("backend",),
-                           donate_argnums=(2, 3, 4))
+# No buffer donation. The large inputs (per-task block columns) never
+# match an output's shape, so XLA cannot reuse them; the JobSpec batch
+# holds the chunk JobSet's own D / C columns, which the epilogue reads
+# after the call; and a chaos retry runs the same chunk thunk, with the
+# same input arrays, again.
+_fleet_core = jax.jit(_core_impl, static_argnames=_STATIC)
+_fleet_fused = jax.jit(_fused_impl, static_argnames=_STATIC + ("backend",))
 
 
 def _warn_saturated(strategy: str, n_sat: int, max_r: int):

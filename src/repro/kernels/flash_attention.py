@@ -21,14 +21,6 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _tpu_compiler_params(**kwargs):
-    """jax renamed pltpu.TPUCompilerParams -> CompilerParams across
-    releases; resolve whichever this install provides."""
-    cls = getattr(pltpu, "CompilerParams", None) or \
-        getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
-
-
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             causal: bool, sm_scale: float, block_q: int, block_k: int,
             softcap):
@@ -106,7 +98,7 @@ def flash_attention(q, k, v, *, causal=True, softcap=None, block_q=128,
             pltpu.VMEM((block_q, 1), jnp.float32),    # running sum
             pltpu.VMEM((block_q, D), jnp.float32),    # output accumulator
         ],
-        compiler_params=_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q.reshape(B * H, Sq, D), k.reshape(B * K, Sk, D),

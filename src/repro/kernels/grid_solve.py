@@ -5,10 +5,10 @@ The XLA reference path (`strategies.spec.grid_solve`, backend="xla")
 evaluates the utility grid, argmaxes it, and then re-evaluates pocd/cost
 at r* as three separate fusion islands with the (J, r_max) grid
 materialized between them. This kernel keeps one job tile's grid entirely
-in VMEM: a (Jt, r_max) utility surface is built from the spec's analytic
+in VMEM: an (r_max, Jt) utility surface is built from the spec's analytic
 closed forms (the same `utility_of` / `pocd_of_spec` / `cost_of_spec`
 closures — there is deliberately no second copy of the math), reduced to
-r* along the lane axis, and pocd/cost/utility at r* written out, so the
+r* along the sublane axis, and pocd/cost/utility at r* written out, so the
 grid never touches HBM.
 
 Composite strategies (spec.components, e.g. `adaptive`) fold their
@@ -17,15 +17,19 @@ are built in registers, U = elementwise max over subs (exactly
 U_adaptive(r) = max_s U_s(r)), and the winning sub id at r* is selected
 with where-masks — `jnp.take_along_axis`, which the XLA closures use, has
 no Mosaic lowering, so the fold is the kernel-side form of the same math
-and is tested bit-identical on r*/choice.
+and is tested identical on r*/choice.
 
-Tile geometry: jobs on the sublane axis, r on the lane axis, JOB_TILE=32.
-The tile is deliberately smaller than pocd_mc's 128: S-Restart's Thm-4
-cost integral evaluates a 128-node Gauss-Legendre quadrature, so its
-intermediate is (Jt, r_max, 128) f32 — 1 MiB at Jt=32, r_max=64, which
-keeps the whole working set (3 sub-strategy grids + quadrature) inside
-VMEM. Partial tiles are masked in-kernel (`pocd_mc.py` idiom): any J
-works with no host-side padding.
+Tile geometry: r on the sublane axis, jobs on the lane axis,
+JOB_TILE=128 jobs per grid step. The wrapper stacks the JobSpec columns
+into one lane-dense (N_COLS, J) operand, so each column enters the kernel
+as a (1, Jt) row that broadcasts against the (r_max, Jt) utility surface
+with no in-kernel reshape; the six outputs leave as two (3, J) slabs (one
+f32, one i32). S-Restart's Thm-4 cost integral evaluates a 128-node
+Gauss-Legendre quadrature on a leading axis: its intermediate is
+(128, r_max, Jt) f32, 4.5 MiB at r_max=65 (r_max pads to 72 sublanes);
+it compiles for v5e within the default scoped-VMEM limit
+(tests/test_tpu_compile.py). A partial last tile needs no mask: lanes
+are independent, and Pallas drops the out-of-range part of a block.
 
 Saturation: Algorithm 1's grid is exact only when r_max exceeds the
 certified bound (`core.optimizer.r_upper_bound`); an argmax landing on
@@ -45,10 +49,8 @@ from ..core import cost as core_cost
 from ..core.utility import JobSpec
 from ..strategies import cost_of_spec, get, pocd_of_spec, utility_of
 
-JOB_TILE = 32
-
-#: JobSpec field order == kernel operand order; the wrapper unpacks the
-#: batched spec into these (J,) f32 columns.
+JOB_TILE = 128
+#: JobSpec field order == row order of the stacked (N_COLS, J) operand
 N_COLS = len(JobSpec._fields)
 
 
@@ -60,66 +62,65 @@ def _sub_specs(spec):
     return (spec,)
 
 
-def _kernel(*refs, strategy: str, r_max: int, n_jobs: int):
-    col_refs = refs[:N_COLS]
-    gl_u_ref, gl_w_ref = refs[N_COLS:N_COLS + 2]
-    out_refs = refs[N_COLS + 2:]
-    r_ref, ch_ref, u_ref, p_ref, c_ref, sat_ref = out_refs
-    # (Jt, 1) job columns broadcast against the (Jt, r_max) lane grid
-    job = JobSpec(*(ref[...][:, None] for ref in col_refs))
-    Jt = job.t_min.shape[0]
+def _first_argmax(vals):
+    """Elementwise index of the first maximum over a list of same-shape
+    arrays, NaN counting as largest: `jnp.argmax(jnp.stack(vals), 0)`
+    without the stack (the XLA closures' form)."""
+    best, idx = vals[0], jnp.zeros(vals[0].shape, jnp.int32)
+    for k, v in enumerate(vals[1:], start=1):
+        take = (v > best) | (jnp.isnan(v) & ~jnp.isnan(best))
+        best = jnp.where(take, v, best)
+        idx = jnp.where(take, k, idx)
+    return idx
+
+
+def _kernel(cols_ref, gl_u_ref, gl_w_ref, f_ref, i_ref, *, strategy: str,
+            r_max: int):
+    # (1, Jt) job rows broadcast against the (r_max, Jt) grid
+    job = JobSpec(*(cols_ref[k:k + 1, :] for k in range(N_COLS)))
+    Jt = cols_ref.shape[1]
     spec = get(strategy)
     subs = _sub_specs(spec)
 
-    # Thm-4's Gauss-Legendre nodes enter as operands (Pallas forbids
-    # captured consts); the closures read them through this scope
+    # Thm-4's Gauss-Legendre nodes enter as (K, 1, 1) operands (Pallas
+    # forbids captured consts); the closures read them through this scope
     with core_cost.quadrature_inputs(gl_u_ref[...], gl_w_ref[...]):
-        _solve_tile(job, Jt, spec, subs, r_max, n_jobs,
-                    r_ref, ch_ref, u_ref, p_ref, c_ref, sat_ref)
+        row = jax.lax.broadcasted_iota(jnp.int32, (r_max, Jt), 0)
+        rs = row.astype(jnp.float32)
+        u = utility_of(subs[0], rs, job)
+        for s in subs[1:]:
+            u = jnp.maximum(u, utility_of(s, rs, job))  # max_s U_s(r)
+        # r* = first argmax over the sublane axis (NaN counts as largest,
+        # as in jnp.argmax)
+        top = jnp.max(u, axis=0, keepdims=True)
+        hit = (u == top) | jnp.isnan(u)
+        i = jnp.min(jnp.where(hit, row, r_max), axis=0, keepdims=True)
+        u_star = jnp.max(jnp.where(row == i, u, -jnp.inf), axis=0,
+                         keepdims=True)
 
-
-def _solve_tile(job, Jt, spec, subs, r_max, n_jobs,
-                r_ref, ch_ref, u_ref, p_ref, c_ref, sat_ref):
-    rs = jax.lax.broadcasted_iota(jnp.float32, (Jt, r_max), 1)
-    u = utility_of(subs[0], rs, job)
-    for s in subs[1:]:
-        u = jnp.maximum(u, utility_of(s, rs, job))   # U(r) = max_s U_s(r)
-    i = jnp.argmax(u, axis=1).astype(jnp.int32)      # r* per job
-    lane = jax.lax.broadcasted_iota(jnp.int32, (Jt, r_max), 1)
-    # select-at-r* via a max over a -inf-masked row: exact (picks the
-    # argmax column's own value) and lane-reduction friendly
-    u_star = jnp.max(jnp.where(lane == i[:, None], u, -jnp.inf), axis=1)
-
-    # pocd/cost at the solved r — evaluated at the scalar r*, the same
-    # arithmetic the XLA reference runs, so the floats match bitwise
-    rf = i.astype(jnp.float32)[:, None]              # (Jt, 1)
-    if len(subs) == 1:
-        choice = jnp.zeros((Jt,), jnp.int32)
-        p_star = pocd_of_spec(spec, rf, job)[:, 0]
-        c_star = cost_of_spec(spec, rf, job)[:, 0]
-    else:
-        su = jnp.stack([utility_of(s, rf, job)[:, 0] for s in subs])
-        choice = jnp.argmax(su, axis=0).astype(jnp.int32)
-        p_star = pocd_of_spec(subs[0], rf, job)[:, 0]
-        c_star = cost_of_spec(subs[0], rf, job)[:, 0]
-        for k, s in enumerate(subs[1:], start=1):
-            hit = choice == k
-            p_star = jnp.where(hit, pocd_of_spec(s, rf, job)[:, 0], p_star)
-            c_star = jnp.where(hit, cost_of_spec(s, rf, job)[:, 0], c_star)
+        # pocd/cost at the solved r, evaluated at the scalar r* as the
+        # XLA reference does
+        rf = i.astype(jnp.float32)                     # (1, Jt)
+        if len(subs) == 1:
+            choice = jnp.zeros_like(i)
+            p_star = pocd_of_spec(spec, rf, job)
+            c_star = cost_of_spec(spec, rf, job)
+        else:
+            choice = _first_argmax([utility_of(s, rf, job) for s in subs])
+            p_star = pocd_of_spec(subs[0], rf, job)
+            c_star = cost_of_spec(subs[0], rf, job)
+            for k, s in enumerate(subs[1:], start=1):
+                hit_k = choice == k
+                p_star = jnp.where(hit_k, pocd_of_spec(s, rf, job), p_star)
+                c_star = jnp.where(hit_k, cost_of_spec(s, rf, job), c_star)
     sat = (i >= r_max - 1).astype(jnp.int32)
 
-    if n_jobs % Jt == 0:
-        valid = None                      # every tile full: no masking cost
-    else:
-        row = jax.lax.broadcasted_iota(jnp.int32, (Jt, 1), 0)[:, 0]
-        valid = pl.program_id(0) * Jt + row < n_jobs
-    mask = lambda x: x if valid is None else jnp.where(valid, x, 0)
-    r_ref[...] = mask(i)
-    ch_ref[...] = mask(choice)
-    u_ref[...] = mask(u_star)
-    p_ref[...] = mask(p_star)
-    c_ref[...] = mask(c_star)
-    sat_ref[...] = mask(sat)
+    f_ref[0:1, :] = u_star
+    f_ref[1:2, :] = p_star
+    f_ref[2:3, :] = c_star
+    i_ref[0:1, :] = i
+    i_ref[1:2, :] = choice
+    i_ref[2:3, :] = sat
 
 
 def grid_solve_pallas(spec, jobs, r_max: int, *, interpret=True):
@@ -130,22 +131,22 @@ def grid_solve_pallas(spec, jobs, r_max: int, *, interpret=True):
     `choice` is the composite sub-strategy pick (zeros for pure specs),
     `sat` flags jobs whose argmax saturated at the grid edge.
     """
-    cols = tuple(jnp.asarray(c, jnp.float32) for c in jobs)
-    J = int(cols[0].shape[0])
-    gl_u, gl_w = core_cost._GL_ACTIVE
+    cols = jnp.stack([jnp.asarray(c, jnp.float32) for c in jobs])
+    J = int(cols.shape[1])
+    gl_u, gl_w = (x.reshape(-1, 1, 1) for x in core_cost._GL_ACTIVE)
     K = int(gl_u.shape[0])
     kernel = functools.partial(_kernel, strategy=spec.name,
-                               r_max=int(r_max), n_jobs=J)
-    col_spec = pl.BlockSpec((JOB_TILE,), lambda i: (i,))
-    gl_spec = pl.BlockSpec((K,), lambda i: (0,))   # replicated per tile
-    f32, i32 = jnp.float32, jnp.int32
-    out = pl.pallas_call(
+                               r_max=int(r_max))
+    gl_spec = pl.BlockSpec((K, 1, 1), lambda i: (0, 0, 0))  # every tile
+    out_spec = pl.BlockSpec((3, JOB_TILE), lambda i: (0, i))
+    f, i = pl.pallas_call(
         kernel,
-        grid=((J + JOB_TILE - 1) // JOB_TILE,),
-        in_specs=[col_spec] * N_COLS + [gl_spec, gl_spec],
-        out_specs=[col_spec] * 6,
-        out_shape=[jax.ShapeDtypeStruct((J,), d)
-                   for d in (i32, i32, f32, f32, f32, i32)],
+        grid=(pl.cdiv(J, JOB_TILE),),
+        in_specs=[pl.BlockSpec((N_COLS, JOB_TILE), lambda i: (0, i)),
+                  gl_spec, gl_spec],
+        out_specs=[out_spec, out_spec],
+        out_shape=[jax.ShapeDtypeStruct((3, J), jnp.float32),
+                   jax.ShapeDtypeStruct((3, J), jnp.int32)],
         interpret=interpret,
-    )(*cols, gl_u, gl_w)
-    return tuple(out)
+    )(cols, gl_u, gl_w)
+    return i[0], i[1], f[0], f[1], f[2], i[2]

@@ -26,8 +26,8 @@ def pocd_mc(u, t_min, beta, D, r, mode="clone", tau_est_frac=0.3,
             tau_kill_gap_frac=0.5, phi=0.25):
     """Monte-Carlo PoCD + cost for a batch of uniform-N jobs.
 
-    Returns (met (J,), cost (J,)). Partial job tiles are masked inside the
-    kernel, so no padding copy of the (J, N, R) uniforms is ever made.
+    Returns (met (J,), cost (J,)). Any J: a partial last job tile needs
+    no padding copy of the uniforms.
     """
     return pocd_mc_pallas(u, t_min, beta, D, r, mode=mode,
                           tau_est_frac=tau_est_frac,

@@ -4,8 +4,10 @@ evaluation hot spot as a literal on-chip MapReduce.
 Map: transform per-attempt uniforms into Pareto execution times, per-task
 minimum over active attempts (the speculative race). Reduce: per-job
 all-tasks-before-deadline indicator + total machine time. One grid step
-processes a tile of jobs; the (jobs_tile, n_tasks, max_attempts) working set
-lives in VMEM (128 x 64 x 8 f32 = 256 KiB).
+processes a tile of 128 jobs. Jobs sit on the lane axis: the wrapper
+moves the (J, N, R) uniforms to (R, N, J), so a tile is R (N, 128) slabs
+with tasks on sublanes, and the slot race and the task reduction are
+leading-axis and sublane reductions that leave lane-dense (1, Jt) rows.
 
 Two entry points share the strategy bodies (`_strategy_outcome`):
 
@@ -15,9 +17,8 @@ Two entry points share the strategy bodies (`_strategy_outcome`):
     once and reused, where three separate launches would redo it per mode.
 
 Neither requires J to divide the job tile: the grid covers ceil(J / tile)
-steps and the last partial tile is masked in-kernel (lanes past J write 0),
-so callers never pad the (J, N, R) uniforms and short batches stop paying
-for a full ghost tile.
+steps, lanes are independent, and Pallas drops the out-of-range part of
+the last block, so callers never pad the uniforms.
 
 Used by the governor's empirical PoCD cross-check and by benchmarks; the
 ragged-trace path uses the segment-reduction JAX implementation (sim/), and
@@ -50,10 +51,10 @@ MODES = tuple(n for n in names() if get(n).tile_outcome is not None)
 
 def _strategy_outcome(att, t_min, tau_est, tau_kill, D, r, *, mode: str,
                       phi: float):
-    """(completion, machine), both (Jt, N), from shared Pareto draws.
+    """(completion, machine), both (N, Jt), from shared Pareto draws.
 
-    att: (Jt, N, R) attempt times; t_min: (Jt, 1, 1); tau_est/tau_kill:
-    (Jt, N); D/r: (Jt, 1). The body is the mode's spec `tile_outcome`.
+    att: (R, N, Jt) attempt times; t_min/tau_est/tau_kill/D/r: (1, Jt)
+    rows. The body is the mode's spec `tile_outcome`.
     """
     spec = get(mode)
     if spec.tile_outcome is None:
@@ -62,95 +63,59 @@ def _strategy_outcome(att, t_min, tau_est, tau_kill, D, r, *, mode: str,
     return spec.tile_outcome(att, t_min, tau_est, tau_kill, D, r, phi=phi)
 
 
-def _tile_prelude(u_ref, tmin_ref, beta_ref, D_ref, n_jobs: int):
-    """Shared per-tile setup: Pareto transform + partial-tile lane mask."""
-    u = u_ref[...]                    # (Jt, N, R)
-    t_min = tmin_ref[...][:, None, None]
-    beta = beta_ref[...][:, None, None]
-    D = D_ref[...][:, None]           # (Jt, 1)
-    Jt = u.shape[0]
-
-    tau_est_base = t_min[:, :, 0]     # (Jt, 1), scaled by fracs below
-    att = t_min * jnp.exp(-jnp.log(u) / beta)     # Pareto via u^(-1/beta)
-    if n_jobs % Jt == 0:
-        valid = None                  # every tile is full: no masking cost
-    else:
-        lane = jax.lax.broadcasted_iota(jnp.int32, (Jt, 1), 0)[:, 0]
-        valid = pl.program_id(0) * Jt + lane < n_jobs  # (Jt,)
-    return att, t_min, tau_est_base, D, valid
-
-
-def _kernel(u_ref, tmin_ref, beta_ref, D_ref, r_ref, met_ref, cost_ref, *,
-            mode: str, tau_est_frac: float, tau_kill_gap_frac: float,
-            phi: float, n_jobs: int):
-    att, t_min, tm2, D, valid = _tile_prelude(u_ref, tmin_ref, beta_ref,
-                                              D_ref, n_jobs)
-    tau_est = tau_est_frac * tm2
-    tau_kill = tau_est + tau_kill_gap_frac * tm2
-    r = r_ref[...][:, None]           # (Jt, 1) int32
-    completion, machine = _strategy_outcome(
-        att, t_min, tau_est, tau_kill, D, r, mode=mode, phi=phi)
-    met = jnp.all(completion <= D, axis=1).astype(jnp.float32)
-    cost = jnp.sum(machine, axis=1)
-    met_ref[...] = met if valid is None else jnp.where(valid, met, 0.0)
-    cost_ref[...] = cost if valid is None else jnp.where(valid, cost, 0.0)
-
-
-def _kernel_all(u_ref, tmin_ref, beta_ref, D_ref, r_ref, met_ref, cost_ref,
-                *, tau_est_frac: float, tau_kill_gap_frac: float, phi: float,
-                n_jobs: int):
-    """Fused multi-mode pass: one Pareto transform feeds all three
-    strategies; met/cost land in (n_modes, Jt) output tiles."""
-    att, t_min, tm2, D, valid = _tile_prelude(u_ref, tmin_ref, beta_ref,
-                                              D_ref, n_jobs)
-    tau_est = tau_est_frac * tm2
-    tau_kill = tau_est + tau_kill_gap_frac * tm2
-    for m, mode in enumerate(MODES):
-        r = r_ref[...][m][:, None]    # (Jt, 1) int32
+def _kernel(u_ref, par_ref, r_ref, met_ref, cost_ref, *, modes,
+            tau_est_frac: float, tau_kill_gap_frac: float, phi: float):
+    """Shared Pareto transform, then one (met, cost) row per mode."""
+    t_min, beta, D = (par_ref[k:k + 1, :] for k in range(3))  # (1, Jt)
+    att = t_min * jnp.exp(-jnp.log(u_ref[...]) / beta)  # Pareto u^(-1/beta)
+    tau_est = tau_est_frac * t_min
+    tau_kill = tau_est + tau_kill_gap_frac * t_min
+    for m, mode in enumerate(modes):
         completion, machine = _strategy_outcome(
-            att, t_min, tau_est, tau_kill, D, r, mode=mode, phi=phi)
-        met = jnp.all(completion <= D, axis=1).astype(jnp.float32)
-        cost = jnp.sum(machine, axis=1)
-        met_ref[m, :] = met if valid is None else jnp.where(valid, met, 0.0)
-        cost_ref[m, :] = cost if valid is None else jnp.where(valid, cost, 0.0)
+            att, t_min, tau_est, tau_kill, D, r_ref[m:m + 1, :], mode=mode,
+            phi=phi)
+        met_ref[m:m + 1, :] = jnp.min(
+            jnp.where(completion <= D, 1.0, 0.0), axis=0, keepdims=True)
+        cost_ref[m:m + 1, :] = jnp.sum(machine, axis=0, keepdims=True)
 
 
-def _grid_of(J: int):
-    return ((J + JOB_TILE - 1) // JOB_TILE,)
+def _call(u, t_min, beta, D, r_rows, modes, *, tau_est_frac,
+          tau_kill_gap_frac, phi, interpret):
+    """One launch over all jobs: u (J, N, R), r_rows (len(modes), J)."""
+    J, N, R = u.shape
+    M = len(modes)
+    kernel = functools.partial(_kernel, modes=modes,
+                               tau_est_frac=tau_est_frac,
+                               tau_kill_gap_frac=tau_kill_gap_frac, phi=phi)
+    att_in = jnp.transpose(u, (2, 1, 0))                    # (R, N, J)
+    par = jnp.stack([t_min, beta, D]).astype(jnp.float32)  # (3, J)
+    row_spec = pl.BlockSpec((M, JOB_TILE), lambda i: (0, i))
+    met, cost = pl.pallas_call(
+        kernel,
+        grid=(pl.cdiv(J, JOB_TILE),),
+        in_specs=[
+            pl.BlockSpec((R, N, JOB_TILE), lambda i: (0, 0, i)),
+            pl.BlockSpec((3, JOB_TILE), lambda i: (0, i)),
+            row_spec,
+        ],
+        out_specs=[row_spec, row_spec],
+        out_shape=[jax.ShapeDtypeStruct((M, J), jnp.float32)] * 2,
+        interpret=interpret,
+    )(att_in, par, r_rows.astype(jnp.int32))
+    return met, cost
 
 
 def pocd_mc_pallas(u, t_min, beta, D, r, *, mode="clone", tau_est_frac=0.3,
                    tau_kill_gap_frac=0.5, phi=0.25, interpret=True):
     """u: (J, N, R) uniforms; per-job t_min/beta/D (J,), r (J,) int32.
 
-    Returns (met (J,) f32, cost (J,) f32). Any J: partial tiles are masked
-    in-kernel, no padding required.
+    Returns (met (J,) f32, cost (J,) f32). Any J: no padding required.
     """
-    J, N, R = u.shape
-    kernel = functools.partial(_kernel, mode=mode, tau_est_frac=tau_est_frac,
-                               tau_kill_gap_frac=tau_kill_gap_frac, phi=phi,
-                               n_jobs=J)
-    met, cost = pl.pallas_call(
-        kernel,
-        grid=_grid_of(J),
-        in_specs=[
-            pl.BlockSpec((JOB_TILE, N, R), lambda i: (i, 0, 0)),
-            pl.BlockSpec((JOB_TILE,), lambda i: (i,)),
-            pl.BlockSpec((JOB_TILE,), lambda i: (i,)),
-            pl.BlockSpec((JOB_TILE,), lambda i: (i,)),
-            pl.BlockSpec((JOB_TILE,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((JOB_TILE,), lambda i: (i,)),
-            pl.BlockSpec((JOB_TILE,), lambda i: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((J,), jnp.float32),
-            jax.ShapeDtypeStruct((J,), jnp.float32),
-        ],
-        interpret=interpret,
-    )(u, t_min, beta, D, r)
-    return met, cost
+    met, cost = _call(u, t_min, beta, D, r[None], (mode,),
+                      tau_est_frac=tau_est_frac,
+                      tau_kill_gap_frac=tau_kill_gap_frac, phi=phi,
+                      interpret=interpret)
+    return met[0], cost[0]
 
 
 def pocd_mc_all_pallas(u, t_min, beta, D, r_modes, *, tau_est_frac=0.3,
@@ -161,30 +126,9 @@ def pocd_mc_all_pallas(u, t_min, beta, D, r_modes, *, tau_est_frac=0.3,
     Returns (met (n_modes, J), cost (n_modes, J)) — one kernel launch, one
     Pareto transform, three strategy evaluations.
     """
-    J, N, R = u.shape
-    M = len(MODES)
-    assert r_modes.shape == (M, J), r_modes.shape
-    kernel = functools.partial(_kernel_all, tau_est_frac=tau_est_frac,
-                               tau_kill_gap_frac=tau_kill_gap_frac, phi=phi,
-                               n_jobs=J)
-    met, cost = pl.pallas_call(
-        kernel,
-        grid=_grid_of(J),
-        in_specs=[
-            pl.BlockSpec((JOB_TILE, N, R), lambda i: (i, 0, 0)),
-            pl.BlockSpec((JOB_TILE,), lambda i: (i,)),
-            pl.BlockSpec((JOB_TILE,), lambda i: (i,)),
-            pl.BlockSpec((JOB_TILE,), lambda i: (i,)),
-            pl.BlockSpec((M, JOB_TILE), lambda i: (0, i)),
-        ],
-        out_specs=[
-            pl.BlockSpec((M, JOB_TILE), lambda i: (0, i)),
-            pl.BlockSpec((M, JOB_TILE), lambda i: (0, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((M, J), jnp.float32),
-            jax.ShapeDtypeStruct((M, J), jnp.float32),
-        ],
-        interpret=interpret,
-    )(u, t_min, beta, D, r_modes)
-    return met, cost
+    J = u.shape[0]
+    assert r_modes.shape == (len(MODES), J), r_modes.shape
+    return _call(u, t_min, beta, D, r_modes, MODES,
+                 tau_est_frac=tau_est_frac,
+                 tau_kill_gap_frac=tau_kill_gap_frac, phi=phi,
+                 interpret=interpret)

@@ -194,9 +194,6 @@ def _cell(arch: str, shape_name: str, multi_pod: bool, verbose=True,
         t_compile = time.time() - t0
 
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        # older jaxlibs return [per-computation dict]; newer a flat dict
-        ca = ca[0] if ca else {}
     ma = compiled.memory_analysis()
     hlo = compiled.as_text()
     if hlo_out is not None:

@@ -28,6 +28,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from .sorting import stable_argsort
 from .trace import JobSet
 
 
@@ -164,7 +165,7 @@ def _rank_among_job(values, job_id, n_jobs):
     break by original index (stable sort), matching `_rank_among_job_scan`.
     """
     T = values.shape[0]
-    order = jnp.lexsort((-values, job_id))
+    order = stable_argsort(job_id, -values)
     counts = jax.ops.segment_sum(jnp.ones((T,), jnp.int32), job_id, n_jobs)
     starts = jnp.cumsum(counts) - counts          # exclusive prefix sum
     ranks_sorted = jnp.arange(T, dtype=jnp.int32) - starts[job_id[order]]

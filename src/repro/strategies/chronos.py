@@ -141,25 +141,29 @@ def build_sresume(key, jobs, r_task, choice_task, p, *, max_r=8,
 
 # ---------------------------------------------------------------------------
 # Pallas tile bodies (shared Pareto draws; see kernels/pocd_mc.py)
+#
+# Layout: att (R, N, Jt) with the attempt slot leading and jobs on lanes;
+# t_min / tau_est / tau_kill / D / r are (1, Jt) rows. Returns
+# (completion, machine), both (N, Jt).
 # ---------------------------------------------------------------------------
 
 
+def _slots(att):
+    return jax.lax.broadcasted_iota(jnp.int32, att.shape, 0)
+
+
 def tile_clone(att, t_min, tau_est, tau_kill, D, r, *, phi):
-    Jt, N, R = att.shape
-    slot = jax.lax.broadcasted_iota(jnp.int32, (Jt, N, R), 2)
-    active = slot <= r[:, :, None]
-    best = jnp.min(jnp.where(active, att, jnp.inf), axis=2)
+    active = _slots(att) <= r
+    best = jnp.min(jnp.where(active, att, jnp.inf), axis=0)
     machine = r.astype(att.dtype) * tau_kill + best
     return best, machine
 
 
 def tile_srestart(att, t_min, tau_est, tau_kill, D, r, *, phi):
-    Jt, N, R = att.shape
-    T1 = att[:, :, 0]
+    T1 = att[0]
     strag = T1 > D
-    extra_slot = jax.lax.broadcasted_iota(jnp.int32, (Jt, N, R - 1), 2)
-    active = (extra_slot < r[:, :, None]) & strag[:, :, None]
-    extras = jnp.min(jnp.where(active, att[:, :, 1:], jnp.inf), axis=2)
+    active = (_slots(att[1:]) < r) & strag
+    extras = jnp.min(jnp.where(active, att[1:], jnp.inf), axis=0)
     w_all = jnp.minimum(T1 - tau_est, extras)
     use = strag & (r > 0)
     completion = jnp.where(use, tau_est + w_all, T1)
@@ -169,13 +173,11 @@ def tile_srestart(att, t_min, tau_est, tau_kill, D, r, *, phi):
 
 
 def tile_sresume(att, t_min, tau_est, tau_kill, D, r, *, phi):
-    Jt, N, R = att.shape
-    T1 = att[:, :, 0]
+    T1 = att[0]
     strag = T1 > D
-    resumed = jnp.maximum(t_min, (1.0 - phi) * att[:, :, 1:])
-    extra_slot = jax.lax.broadcasted_iota(jnp.int32, (Jt, N, R - 1), 2)
-    active = (extra_slot <= r[:, :, None]) & strag[:, :, None]
-    w_new = jnp.min(jnp.where(active, resumed, jnp.inf), axis=2)
+    resumed = jnp.maximum(t_min, (1.0 - phi) * att[1:])
+    active = (_slots(resumed) <= r) & strag
+    w_new = jnp.min(jnp.where(active, resumed, jnp.inf), axis=0)
     completion = jnp.where(strag, tau_est + w_new, T1)
     machine = jnp.where(
         strag, tau_est + r.astype(att.dtype) * (tau_kill - tau_est) + w_new,

@@ -60,7 +60,9 @@ class StrategySpec(NamedTuple):
                                of one extra attempt (host-side) [optional]
       choose(r, jobs_spec)  -> (J,) int32 per-job sub-strategy id [optional]
       tile_outcome(att, t_min, tau_est, tau_kill, D, r, *, phi)
-          -> (completion, machine) Pallas tile body          [optional]
+          -> (completion, machine) Pallas tile body; att (R, N, Jt)
+             with jobs on lanes, per-job operands (1, Jt) rows,
+             outputs (N, Jt)                                 [optional]
       allocate(jobs_spec, U, cost, budget) -> (J,) int32 r per job
           — budget-allocation policy consulted ONLY by the coupled
           solver (`repro.coupled`): replaces the Lagrangian dual with

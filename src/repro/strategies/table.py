@@ -12,6 +12,7 @@ from __future__ import annotations
 from typing import NamedTuple
 
 import jax.numpy as jnp
+from jax import lax
 
 
 class AttemptTable(NamedTuple):
@@ -26,19 +27,36 @@ class AttemptTable(NamedTuple):
     is_primary: jnp.ndarray   # bool
 
 
+def row_major(T: int, A: int):
+    """(task, attempt) of each row of a (T, A) grid flattened row-major.
+
+    Tables flatten by gathering at these indices, not by `reshape`: a
+    TPU compiler lays a (T, A) array with A attempts on lanes padded to
+    128, and its flattening reshape compiles to code that grows with T
+    (minutes for a 10^6-task trace), while the gather compiles in well
+    under a second and moves the same values.
+    """
+    i = lax.iota(jnp.int32, T * A)
+    return i // A, i % A
+
+
 def assemble(jobs, rel, dur, hold_cap, can_win, active) -> AttemptTable:
     """Flatten (T, A) per-attempt arrays into a (T*A,) AttemptTable.
 
     Layout contract (relied on by the replay's primary-slice fast path):
     row t*A + a is attempt a of task t, and attempt 0 is the primary.
+    Inputs may be (T, A), (T, 1) or (1, A).
     """
     T, A = dur.shape
-    flat = lambda x: jnp.broadcast_to(x, (T, A)).reshape(-1)
-    task_id = jnp.repeat(jnp.arange(T, dtype=jnp.int32), A)
-    is_primary = flat(jnp.arange(A)[None, :] == 0)
+    task_id, att = row_major(T, A)
+
+    def flat(x):
+        rows, cols = x.shape
+        return x[task_id if rows > 1 else 0, att if cols > 1 else 0]
+
     return AttemptTable(
         task_id=task_id, job_id=jobs.job_id[task_id],
         rel_offset=flat(rel).astype(jnp.float32),
         dur=flat(dur).astype(jnp.float32),
         hold_cap=flat(hold_cap).astype(jnp.float32),
-        can_win=flat(can_win), active=flat(active), is_primary=is_primary)
+        can_win=flat(can_win), active=flat(active), is_primary=att == 0)

@@ -236,6 +236,38 @@ def test_retry_and_corruption_are_invisible_and_deterministic():
     assert kinds.count("retry") == 3 and kinds.count("corrupt") == 1
 
 
+@pytest.mark.parametrize("path", ["fused", "staged", "cluster"])
+def test_retry_reads_no_donated_buffer(path):
+    """A retried chunk re-runs its thunk on the same input arrays, so a
+    chunk program must not donate them. XLA:CPU honours donation (checked
+    first), so a donating chunk program fails this test here with
+    "Array has been deleted"; on the chip, chip_smoke.py phase (e) runs
+    the same retry."""
+    import jax.numpy as jnp
+    x = jnp.ones((8,))
+    jax.jit(lambda v: v + 1, donate_argnums=0)(x)
+    assert x.is_deleted()
+
+    plan = FaultPlan(events=(FaultEvent("chunk_fail", 0),
+                             FaultEvent("corrupt", 1)), seed=5)
+    if path == "cluster":
+        def run(**kw):
+            return run_cluster_fleet_strategy(KEY, JOBS, "sresume", P,
+                                              slots=300, chunk_jobs=CHUNK,
+                                              **kw)
+    else:
+        # reps=1: the job-level block columns then match an output's
+        # shape, so a donating program would really reuse (delete) them
+        def run(**kw):
+            return run_fleet_strategy(KEY, JOBS, "sresume", P,
+                                      chunk_jobs=CHUNK, reps=1,
+                                      fused=path == "fused", **kw)
+    ctx = ChaosContext(plan, backoff_base=0.0)
+    out = run(chaos=ctx)
+    assert [k for _, k, _ in ctx.records].count("retry") == 2
+    assert outputs_equal(run(), out)
+
+
 def test_empty_plan_matches_chaos_off():
     base = _flat()
     out = _flat(chaos=ChaosContext(EMPTY_PLAN))

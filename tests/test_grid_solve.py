@@ -45,12 +45,11 @@ def specs_of(jobs, r_min=0.0):
 
 
 @pytest.mark.parametrize("strategy", OPTIMIZED)
-@pytest.mark.parametrize("n_jobs,r_max", [(37, 9), (64, 33)])
+@pytest.mark.parametrize("n_jobs,r_max", [(37, 9), (64, 33), (256, 9)])
 def test_pallas_matches_xla(strategy, n_jobs, r_max):
     """r*/choice/sat exact, floats within tolerance, for every optimized
-    strategy on heterogeneous multi-class jobs. n_jobs=37 exercises the
-    in-kernel partial-tile mask (37 % JOB_TILE != 0); 64 the full-tile
-    fast path."""
+    strategy on heterogeneous multi-class jobs. n_jobs=37 and 64 are one
+    partial tile (JOB_TILE=128); 256 is two full tiles."""
     jobs = make_jobset("paper-hadoop", n_jobs=n_jobs, seed=2)
     specs = specs_of(jobs)
     xla = solve_jobs(strategy, specs, r_max, backend="xla")
