@@ -94,11 +94,14 @@ def solve_grid(strategy: str, job: JobSpec, r_max: int | None = None) -> Solutio
     """
     with obs_trace.span("optimizer.solve_grid", strategy=strategy) as sp:
         if r_max is None:
-            u0 = float(utility(strategy, jnp.float32(0.0), job))
+            u0 = utility(strategy, jnp.float32(0.0), job)
+            with obs_trace.span("d2h.wait"):
+                u0 = float(u0)
             r_max = max(r_upper_bound(strategy, job, u0), 2)
         sp.set(r_max=int(r_max))
-        r, u, p, c = jax.device_get(
-            _solve_grid_device(strategy, job, int(r_max)))
+        out = _solve_grid_device(strategy, job, int(r_max))
+        with obs_trace.span("d2h.wait"):
+            r, u, p, c = jax.device_get(out)
         return Solution(strategy, int(r), float(u), float(p), float(c))
 
 

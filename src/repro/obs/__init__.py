@@ -6,7 +6,8 @@ Three pillars:
 * `obs.trace` / `obs.export` — host-side nested spans at every pipeline
   stage boundary, with dispatch-vs-execute fencing; exported as
   Chrome-trace / Perfetto JSON or a compact text summary. Off by default;
-  zero-cost when off.
+  zero-cost when off. While the JAX profiler collects, every span is also
+  a profiler annotation, on the device trace's clock.
 * `obs.metrics` — functional `CapacityMetrics` pytrees threaded through
   the jitted capacity replay (queue-depth histograms, occupancy integrals,
   speculative launch/kill counters, busy-period windows), reduced
@@ -16,7 +17,7 @@ Three pillars:
   r* governor hook.
 """
 from .trace import (Tracer, disable, enable, enabled, fenced, get_tracer,
-                    profile, span)
+                    span)
 from .export import (stage_breakdown, summary, to_chrome_trace,
                      write_chrome_trace)
 from .metrics import (CapacityMetrics, capacity_metrics, combine_windows,
@@ -36,7 +37,7 @@ def __getattr__(name):
 
 __all__ = [
     "Tracer", "enable", "disable", "enabled", "span", "fenced",
-    "get_tracer", "profile",
+    "get_tracer",
     "to_chrome_trace", "write_chrome_trace", "summary", "stage_breakdown",
     "CapacityMetrics", "capacity_metrics", "reduce_reps",
     "reduce_reps_host", "combine_windows",

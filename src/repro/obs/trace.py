@@ -25,20 +25,29 @@ byte-identical program schedule to a build without this module. Overhead
 with tracing ON is gated in CI (< 3% on the trace_sim_full smoke — see
 benchmarks/obs_overhead.py).
 
-An opt-in bridge to `jax.profiler.trace` (`profile(...)`) captures the
-device-level timeline for deep dives; the span layer stays the cheap,
-always-available view.
+Profiler clock: whenever the JAX profiler is collecting
+(`jax.profiler.start_trace` / `trace`), every span is also a
+`jax.profiler.TraceAnnotation` of the same name, so the program's spans
+land in the trace's host plane on the same clock as the device ops. The
+switch is the profiler itself; the tracer above is independent of it.
+With only the profiler on, the tracer records nothing and `fenced`
+annotates the dispatch without `block_until_ready`: the device plane
+already shows execution, and a profiled run keeps the un-traced schedule.
 """
 from __future__ import annotations
 
-import contextlib
 import threading
 import time
 from dataclasses import dataclass, field
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["Span", "Tracer", "enable", "disable", "enabled", "get_tracer",
-           "span", "fenced", "profile"]
+           "span", "fenced"]
+
+#: True while the JAX profiler is collecting host annotations
+_profiling = TraceAnnotation.is_enabled
 
 
 @dataclass
@@ -58,13 +67,40 @@ class Span:
         return end - self.start_ns
 
 
-class _SpanCtx:
-    """Context manager recording one Span on the owning tracer."""
-    __slots__ = ("_tracer", "span")
+class _ProfilerSpan:
+    """A span only the profiler sees: a TraceAnnotation behind the
+    disabled span's interface (`set` is a no-op, `span` is None)."""
+    __slots__ = ("_name", "_annotation")
+    span = None
 
-    def __init__(self, tracer: "Tracer", span_: Span):
+    def __init__(self, name: str):
+        self._name = name
+        self._annotation = None
+
+    def set(self, **attrs):
+        return self
+
+    def __enter__(self):
+        # a TraceAnnotation's interval starts when it is built
+        self._annotation = TraceAnnotation(self._name)
+        self._annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._annotation.__exit__(*exc)
+        return False
+
+
+class _SpanCtx:
+    """Context manager recording one Span on the owning tracer, and on the
+    profiler's clock through `annotation` when one is given."""
+    __slots__ = ("_tracer", "span", "_annotation")
+
+    def __init__(self, tracer: "Tracer", span_: Span,
+                 annotation: Optional[_ProfilerSpan] = None):
         self._tracer = tracer
         self.span = span_
+        self._annotation = annotation
 
     def set(self, **attrs):
         self.span.attrs.update(attrs)
@@ -72,16 +108,20 @@ class _SpanCtx:
 
     def __enter__(self):
         self._tracer._push(self.span)
+        if self._annotation is not None:
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc):
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         self._tracer._pop(self.span)
         return False
 
 
 class _NoopCtx:
-    """Shared do-nothing span: the cost of a disabled span is one attribute
-    load and two no-op calls."""
+    """Shared do-nothing span: with the tracer and the profiler off, a span
+    costs one profiler check and two no-op calls."""
     __slots__ = ()
     span = None
 
@@ -112,7 +152,8 @@ class Tracer:
     # -- span recording ----------------------------------------------------
     def span(self, name: str, kind: str = "stage", **attrs) -> _SpanCtx:
         return _SpanCtx(self, Span(name=name, start_ns=0, kind=kind,
-                                   attrs=dict(attrs)))
+                                   attrs=dict(attrs)),
+                        _ProfilerSpan(name) if _profiling() else None)
 
     def _stack(self) -> list:
         st = getattr(self._local, "stack", None)
@@ -189,12 +230,16 @@ def span(name: str, kind: str = "stage", **attrs):
     """The instrumentation entry every pipeline stage uses.
 
     Disabled: returns a shared no-op context manager (no allocation beyond
-    the kwargs dict the caller built). Enabled: records a Span on the
-    global tracer.
+    the kwargs dict the caller built), unless the JAX profiler is
+    collecting, in which case the span is a TraceAnnotation of `name`.
+    Enabled: records a Span on the global tracer, annotated likewise while
+    the profiler collects.
     """
-    if not _ENABLED:
-        return _NOOP
-    return _TRACER.span(name, kind=kind, **attrs)
+    if _ENABLED:
+        return _TRACER.span(name, kind=kind, **attrs)
+    if _profiling():
+        return _ProfilerSpan(name)
+    return _NOOP
 
 
 def _cache_size(fn) -> Optional[int]:
@@ -211,12 +256,14 @@ def fenced(name: str, fn, /, *args, **kwargs):
     """Call `fn(*args, **kwargs)` under a dispatch span, then block on its
     outputs under an execute span, attributing compile vs execute time.
 
-    With tracing disabled this is a plain call — crucially there is no
-    `block_until_ready`, so the async dispatch pipeline (and therefore the
-    exact program schedule) of an un-traced run is untouched.
+    With tracing disabled there is no `block_until_ready`, so the async
+    dispatch pipeline (and therefore the exact program schedule) of an
+    un-traced run is untouched: a plain call, under the dispatch span
+    alone while the JAX profiler collects.
     """
     if not _ENABLED:
-        return fn(*args, **kwargs)
+        with span(name, kind="dispatch"):
+            return fn(*args, **kwargs)
     import jax
     before = _cache_size(fn)
     with _TRACER.span(name, kind="dispatch") as sp:
@@ -228,16 +275,3 @@ def fenced(name: str, fn, /, *args, **kwargs):
         jax.block_until_ready(out)
     return out
 
-
-@contextlib.contextmanager
-def profile(log_dir: str):
-    """Opt-in deep-dive bridge: wrap a region in `jax.profiler.trace`.
-
-    The span layer answers "which stage, compile or execute"; this captures
-    the full device-level op timeline (TensorBoard / Perfetto) when that is
-    not enough. Never enabled implicitly — profiling has real overhead.
-    """
-    import jax
-    with span("jax.profiler", log_dir=log_dir):
-        with jax.profiler.trace(log_dir):
-            yield

@@ -94,13 +94,15 @@ def _solve_epoch(strategy: str, t_min_fit, beta_fit, reqs: RequestTrace,
     vmapped XLA reference otherwise); both int32 columns come back in one
     batched device->host transfer rather than one sync each.
     """
-    specs = _epoch_jobspecs(t_min_fit, beta_fit, reqs, p, theta, r_min,
-                            width)
-    r, choice, _, _, _, _ = solve_jobs_jit(strategy, specs, max_r + 1,
-                                           backend=backend)
-    n = reqs.n_requests
-    r, choice = jax.device_get((r, choice))
-    return np.asarray(r)[:n], np.asarray(choice)[:n]
+    with obs_trace.span("serve.solve"):
+        specs = _epoch_jobspecs(t_min_fit, beta_fit, reqs, p, theta, r_min,
+                                width)
+        r, choice, _, _, _, _ = solve_jobs_jit(strategy, specs, max_r + 1,
+                                               backend=backend)
+        n = reqs.n_requests
+        with obs_trace.span("d2h.wait"):
+            r, choice = jax.device_get((r, choice))
+        return np.asarray(r)[:n], np.asarray(choice)[:n]
 
 
 def _serve_chunk(key, reqs: RequestTrace, r, choice, *, strategy, p,
@@ -111,11 +113,12 @@ def _serve_chunk(key, reqs: RequestTrace, r, choice, *, strategy, p,
     machine = np.empty(n, np.float32)
     for lo in range(0, n, window):
         hi = min(lo + window, n)
-        c, m = serve_window(
-            key, reqs.rid[lo:hi], reqs.t_min[lo:hi], reqs.beta[lo:hi],
-            reqs.D[lo:hi], r[lo:hi], choice[lo:hi], strategy=strategy,
-            p=p, max_r=max_r, oracle=oracle, width=window,
-            sharding=sharding)
+        with obs_trace.span("serve.window"):
+            c, m = serve_window(
+                key, reqs.rid[lo:hi], reqs.t_min[lo:hi], reqs.beta[lo:hi],
+                reqs.D[lo:hi], r[lo:hi], choice[lo:hi], strategy=strategy,
+                p=p, max_r=max_r, oracle=oracle, width=window,
+                sharding=sharding)
         completion[lo:hi], machine[lo:hi] = c, m
     return completion, machine
 
@@ -197,7 +200,8 @@ def serve_trace(key, reqs, p: Optional[SimParams] = None, *,
             completion, machine = _serve_chunk(
                 key, reqs, r, ch, strategy=strategy, p=p, max_r=max_r,
                 oracle=oracle, window=window, sharding=sharding)
-            acc.add(request_result(reqs, completion, machine), n_jobs=n)
+            with obs_trace.span("serve.combine"):
+                acc.add(request_result(reqs, completion, machine), n_jobs=n)
             sum_r += float(r.sum())
             n_hedged += int((r > 0).sum())
         else:
@@ -219,62 +223,69 @@ def serve_trace(key, reqs, p: Optional[SimParams] = None, *,
                 window_name="serve",
                 on_resolve=lambda sol, fit: fits.append(fit))
             for lo in range(0, n, refit_every):
-                epoch = reqs.slice(lo, min(lo + refit_every, n))
-                e = epoch.n_requests
-                probe = np.asarray(epoch.rid) % probe_every == 0
-                fit = gov.last_fit
-                if strategy == "auto":
-                    epoch_strategy = (gov.decision.strategy
-                                      if gov.decision is not None
-                                      else _UNHEDGED)
-                else:
-                    epoch_strategy = strategy
-                if not optimized:
-                    r, ch = zeros(e), zeros(e)
-                elif r_override is not None:
-                    r, ch = np.full(e, int(r_override), np.int32), zeros(e)
-                elif fit is None or epoch_strategy == _UNHEDGED:
-                    epoch_strategy = _UNHEDGED   # cold: no tail belief yet
-                    r, ch = zeros(e), zeros(e)
-                else:
-                    r, ch = _solve_epoch(
-                        epoch_strategy, fit.t_min, fit.beta, epoch, p,
-                        theta, r_min, max_r, refit_every, backend=backend)
-                epoch_strategies.append(epoch_strategy)
+                with obs_trace.span("serve.epoch"):
+                    epoch = reqs.slice(lo, min(lo + refit_every, n))
+                    e = epoch.n_requests
+                    probe = np.asarray(epoch.rid) % probe_every == 0
+                    fit = gov.last_fit
+                    if strategy == "auto":
+                        epoch_strategy = (gov.decision.strategy
+                                          if gov.decision is not None
+                                          else _UNHEDGED)
+                    else:
+                        epoch_strategy = strategy
+                    if not optimized:
+                        r, ch = zeros(e), zeros(e)
+                    elif r_override is not None:
+                        r = np.full(e, int(r_override), np.int32)
+                        ch = zeros(e)
+                    elif fit is None or epoch_strategy == _UNHEDGED:
+                        # cold: no tail belief yet
+                        epoch_strategy = _UNHEDGED
+                        r, ch = zeros(e), zeros(e)
+                    else:
+                        r, ch = _solve_epoch(
+                            epoch_strategy, fit.t_min, fit.beta, epoch, p,
+                            theta, r_min, max_r, refit_every,
+                            backend=backend)
+                    epoch_strategies.append(epoch_strategy)
 
-                completion = np.empty(e, np.float32)
-                machine = np.empty(e, np.float32)
-                hedged = ~probe
-                for mask, strat, rr, cc in (
-                        (hedged, epoch_strategy, r, ch),
-                        (probe, _UNHEDGED, zeros(e), zeros(e))):
-                    idx = np.flatnonzero(mask)
-                    if idx.size == 0:
-                        continue
-                    c, m = _serve_chunk(
-                        key, _subset(epoch, idx), rr[idx], cc[idx],
-                        strategy=strat, p=p, max_r=max_r, oracle=oracle,
-                        window=window, sharding=sharding)
-                    completion[idx], machine[idx] = c, m
-                if epoch_strategy != _UNHEDGED:
-                    sum_r += float(r[hedged].sum())
-                    n_hedged += int((r[hedged] > 0).sum())
-                n_probes += int(probe.sum())
-                acc.add(request_result(epoch, completion, machine),
-                        n_jobs=e)
-                # completed exploration traffic drives the PR 6
-                # observe -> refit -> re-solve hook; the resolve fires on
-                # the epoch's last probe, so the fresh fit and decision
-                # govern exactly the next epoch
-                if r_override is None:
-                    for x in completion[probe]:
-                        gov.observe(float(x))
+                    completion = np.empty(e, np.float32)
+                    machine = np.empty(e, np.float32)
+                    hedged = ~probe
+                    for mask, strat, rr, cc in (
+                            (hedged, epoch_strategy, r, ch),
+                            (probe, _UNHEDGED, zeros(e), zeros(e))):
+                        idx = np.flatnonzero(mask)
+                        if idx.size == 0:
+                            continue
+                        c, m = _serve_chunk(
+                            key, _subset(epoch, idx), rr[idx], cc[idx],
+                            strategy=strat, p=p, max_r=max_r,
+                            oracle=oracle, window=window, sharding=sharding)
+                        completion[idx], machine[idx] = c, m
+                    if epoch_strategy != _UNHEDGED:
+                        sum_r += float(r[hedged].sum())
+                        n_hedged += int((r[hedged] > 0).sum())
+                    n_probes += int(probe.sum())
+                    with obs_trace.span("serve.combine"):
+                        acc.add(request_result(epoch, completion, machine),
+                                n_jobs=e)
+                    # completed exploration traffic drives the PR 6
+                    # observe -> refit -> re-solve hook; the resolve fires
+                    # on the epoch's last probe, so the fresh fit and
+                    # decision govern exactly the next epoch
+                    if r_override is None:
+                        with obs_trace.span("serve.governor"):
+                            for x in completion[probe]:
+                                gov.observe(float(x))
 
     result = acc.finalize()
+    with obs_trace.span("d2h.wait"):
+        utility = float(net_utility(result.pocd, result.mean_cost, r_min,
+                                    theta))
     return ServeOutput(
-        strategy=requested, result=result,
-        utility=float(net_utility(result.pocd, result.mean_cost,
-                                  r_min, theta)),
+        strategy=requested, result=result, utility=utility,
         latency=latency_summary(result),
         mean_r=(sum_r / max(n_hedged, 1)), n_probes=n_probes,
         n_refits=len(fits), fits=tuple(fits),

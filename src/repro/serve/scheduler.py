@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import JobSpec, Solution, solve
+from ..obs import trace as obs_trace
 from ..sim.strategies import SimParams
 from ..sim.trace import JobSet
 from ..strategies import get
@@ -106,7 +107,11 @@ def serve_window(key, rids, t_min, beta, D, r, choice, *, strategy: str,
         cols = tuple(jax.device_put(c, sharding) for c in cols)
     completion, machine = _window_core(
         key, *cols, strategy=strategy, p=p, max_r=max_r, oracle=oracle)
-    return (np.asarray(completion)[:n], np.asarray(machine)[:n])
+    with obs_trace.span("d2h.wait"):
+        completion = np.asarray(completion)
+    with obs_trace.span("d2h.wait"):
+        machine = np.asarray(machine)
+    return completion[:n], machine[:n]
 
 
 # ---------------------------------------------------------------------------

@@ -6,6 +6,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from ..obs import trace as obs_trace
 from .trace import JobSet
 
 
@@ -71,7 +72,8 @@ def request_result(reqs, completion, machine) -> SimResult:
 def latency_summary(result: SimResult) -> dict:
     """Host-side latency percentiles of a result's completion column."""
     import numpy as np
-    lat = np.asarray(result.job_completion, np.float64)
+    with obs_trace.span("d2h.wait"):
+        lat = np.asarray(result.job_completion, np.float64)
     return {"p50": float(np.percentile(lat, 50)),
             "p95": float(np.percentile(lat, 95)),
             "p99": float(np.percentile(lat, 99)),
@@ -112,9 +114,12 @@ class StreamCombiner:
     def add(self, result: SimResult, n_jobs: int, queue=None,
             capacity=None) -> None:
         import numpy as np
-        self._met.append(np.asarray(result.job_met))
-        self._completion.append(np.asarray(result.job_completion))
-        self._cost.append(np.asarray(result.job_cost))
+        with obs_trace.span("d2h.wait"):
+            self._met.append(np.asarray(result.job_met))
+        with obs_trace.span("d2h.wait"):
+            self._completion.append(np.asarray(result.job_completion))
+        with obs_trace.span("d2h.wait"):
+            self._cost.append(np.asarray(result.job_cost))
         self._weights.append(float(n_jobs))
         if queue is not None:
             # paired with this chunk's weight explicitly, so a caller
@@ -134,13 +139,14 @@ class StreamCombiner:
         import numpy as np
         if not self._met:
             raise ValueError("StreamCombiner.finalize before any add()")
-        met = jnp.asarray(np.concatenate(self._met))
-        completion = jnp.asarray(np.concatenate(self._completion))
-        cost = jnp.asarray(np.concatenate(self._cost))
-        return SimResult(
-            pocd=jnp.mean(met.astype(jnp.float32)), job_met=met,
-            job_completion=completion, job_cost=cost,
-            mean_cost=jnp.mean(cost))
+        with obs_trace.span("combiner.finalize"):
+            met = jnp.asarray(np.concatenate(self._met))
+            completion = jnp.asarray(np.concatenate(self._completion))
+            cost = jnp.asarray(np.concatenate(self._cost))
+            return SimResult(
+                pocd=jnp.mean(met.astype(jnp.float32)), job_met=met,
+                job_completion=completion, job_cost=cost,
+                mean_cost=jnp.mean(cost))
 
     def finalize_queue(self):
         """Weighted-combined queue metrics (None when no chunk had any)."""

@@ -4,7 +4,8 @@ Pins the layer's three contracts (DESIGN.md §15):
 
 * tracing OFF is free — `span` returns a shared no-op, `fenced` degrades
   to a plain call, and the instrumented run's metric payloads are bitwise
-  identical to an uninstrumented run's;
+  identical to an uninstrumented run's; while the JAX profiler collects,
+  spans are annotations on its clock and `fenced` still never blocks;
 * the `CapacityMetrics` pytree is a pure function of the replay arrays —
   histogram mass equals the dispatched-attempt count, and the reduced
   pytree is bit-identical across mesh shapes, pad+mask overrides, and the
@@ -125,6 +126,113 @@ def test_fenced_disabled_is_plain_call():
     assert obs_trace.fenced("demo", fn, 41) == 42
     assert calls == [41]
     assert obs_trace.get_tracer().closed_spans() == []
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+def _profiled_host_events(trace_dir, prefixes):
+    """(name, start_ns, end_ns) of the host events of the one profiler
+    trace under `trace_dir` whose names start with one of `prefixes`."""
+    (path,) = trace_dir.glob("**/*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    return sorted(((e.name, e.start_ns, e.end_ns) for p in data.planes
+                   if p.name.startswith("/host:") for line in p.lines
+                   for e in line.events if e.name.startswith(prefixes)),
+                  key=lambda e: (e[1], -e[2]))
+
+
+def _within(inner, outer):
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+def test_profiler_spans_tile_online_serve_epochs(tmp_path):
+    """With only the JAX profiler on, a tiny online `serve_trace` (1,000
+    requests, epochs of 100, every 5th a probe, windows of 32) leaves its
+    spans in the trace's host plane, nested as the loop runs them, and one
+    `d2h.wait` per blocking device->host read. Per epoch: 80 hedged + 20
+    probe requests = 3 + 1 windows with two reads each, three reads in
+    the combiner, and after the first epoch's refit one solve read and
+    one `solve_grid` read per Chronos strategy at every refit."""
+    from repro.serve import make_requests, serve_trace
+    reqs = make_requests("request-storm", n_requests=1000, seed=0)
+    with jax.profiler.trace(str(tmp_path)):
+        out = serve_trace(KEY, reqs, strategy="sresume", window=32,
+                          refit_every=100, probe_every=5, min_samples=16)
+    assert obs_trace.get_tracer().closed_spans() == []
+    events = _profiled_host_events(
+        tmp_path, ("serve.", "d2h.wait", "optimizer.", "combiner."))
+    by = {}
+    for e in events:
+        by.setdefault(e[0], []).append(e)
+    n_chronos = len(names(kind="chronos"))
+    assert out.n_refits == 10
+    assert {k: len(v) for k, v in by.items()} == {
+        "serve.trace": 1, "serve.epoch": 10, "serve.solve": 9,
+        "serve.window": 40, "serve.combine": 10, "serve.governor": 10,
+        "optimizer.solve_grid": 10 * n_chronos, "combiner.finalize": 1,
+        "d2h.wait": 171}
+    (unit,) = by["serve.trace"]
+    epochs = by["serve.epoch"]
+    assert all(_within(e, unit) for e in epochs)
+    children = ("serve.solve", "serve.window", "serve.combine",
+                "serve.governor")
+    for name in children:
+        assert all(any(_within(c, e) for e in epochs) for c in by[name])
+    assert all(any(_within(g, c) for c in by["serve.governor"])
+               for g in by["optimizer.solve_grid"])
+    inner = [c for name in children for c in by[name]]
+    d2h_per_epoch = [sum(_within(w, e) for w in by["d2h.wait"])
+                     for e in epochs]
+    # every wait inside an epoch sits in one of its four children
+    assert sum(d2h_per_epoch) == sum(
+        any(_within(w, c) for c in inner) for w in by["d2h.wait"])
+    cold = 4 * 2 + 3 + n_chronos
+    assert d2h_per_epoch == [cold] + [cold + 1] * 9
+    # the two reads after the loop: the utility and the latency summary
+    assert len(by["d2h.wait"]) - sum(d2h_per_epoch) == 2
+
+
+def test_profiler_only_fenced_does_not_block(tmp_path, monkeypatch):
+    """Profiler on, tracer off: `fenced` annotates the dispatch and never
+    calls `block_until_ready`, the tracer records nothing, and `span`
+    hands out annotations. Both off: `span` is the shared no-op again."""
+    blocked = []
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: blocked.append(x) or x)
+    fn = jax.jit(lambda x: x * 2.0)
+    with jax.profiler.trace(str(tmp_path)):
+        assert obs_trace.span("a") is not obs_trace.span("b")
+        with obs_trace.span("demo.outer", n=1) as sp:
+            assert sp.set(n=2) is sp and sp.span is None
+            out = obs_trace.fenced("demo.fenced", fn, np.float32(3.0))
+    assert float(out) == 6.0
+    assert blocked == []
+    assert obs_trace.get_tracer().closed_spans() == []
+    assert obs_trace.span("a") is obs_trace.span("b")
+    events = _profiled_host_events(tmp_path, ("demo.",))
+    assert [e[0] for e in events] == ["demo.outer", "demo.fenced"]
+    assert _within(events[1], events[0])
+
+
+def test_tracer_and_profiler_both_record(tmp_path):
+    """Tracer and profiler on together: each span is a tracer Span and a
+    profiler annotation of the same name and nesting."""
+    import time
+    obs_trace.enable()
+    with jax.profiler.trace(str(tmp_path)):
+        with obs_trace.span("demo.outer"):
+            with obs_trace.span("demo.inner", kind="dispatch"):
+                time.sleep(0.002)
+    spans = {s.name: s for s in obs_trace.get_tracer().closed_spans()}
+    assert set(spans) == {"demo.outer", "demo.inner"}
+    assert spans["demo.inner"].depth == 1
+    events = _profiled_host_events(tmp_path, ("demo.",))
+    assert [e[0] for e in events] == ["demo.outer", "demo.inner"]
+    assert _within(events[1], events[0])
+    assert events[1][2] - events[1][1] >= 2e6
 
 
 # ---------------------------------------------------------------------------
