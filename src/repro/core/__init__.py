@@ -8,8 +8,8 @@ from .pareto import ParetoParams, pdf, cdf, sf, mean, sample, fit_mle, min_of_n_
 from .pocd import pocd_clone, pocd_srestart, pocd_sresume
 from .cost import cost_clone, cost_srestart, cost_sresume
 from .utility import JobSpec, utility, gamma, pocd_of, cost_of
-from .optimizer import (Solution, solve, solve_grid, solve_batch,
-                        solve_batch_jit, solve_algorithm1)
+from .optimizer import (Solution, solve, solve_grid, solve_strategies,
+                        solve_batch, solve_batch_jit, solve_algorithm1)
 from .estimator import (ProgressReport, estimate_completion_chronos,
                         estimate_completion_naive, is_straggler, handoff_offset)
 from . import theory
@@ -20,7 +20,8 @@ __all__ = [
     "min_of_n_mean", "pocd_clone", "pocd_srestart", "pocd_sresume",
     "cost_clone", "cost_srestart", "cost_sresume", "JobSpec",
     "utility", "gamma", "pocd_of", "cost_of", "Solution", "solve",
-    "solve_grid", "solve_batch", "solve_batch_jit", "solve_algorithm1",
+    "solve_grid", "solve_strategies", "solve_batch", "solve_batch_jit",
+    "solve_algorithm1",
     "ProgressReport", "estimate_completion_chronos", "multiwave",
     "estimate_completion_naive", "is_straggler", "handoff_offset", "theory",
 ]

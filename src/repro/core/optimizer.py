@@ -13,7 +13,9 @@ Two implementations, tested to agree:
    (cost grows at least linearly in r while the utility term is bounded above
    by lg(1 - R_min), so no maximizer can exist beyond the bound). This is
    exact, jit-friendly, and solves millions of jobs per second under vmap —
-   the form the StepGovernor and the serving scheduler use online.
+   the form the serving scheduler uses online. `solve_strategies` solves one
+   job over a whole strategy set on one grid in one program: the re-solve of
+   the tail and step governors.
 """
 from __future__ import annotations
 
@@ -105,13 +107,63 @@ def solve_grid(strategy: str, job: JobSpec, r_max: int | None = None) -> Solutio
         return Solution(strategy, int(r), float(u), float(p), float(c))
 
 
+@functools.partial(jax.jit, static_argnames=("strategies", "r_max"))
+def _solve_strategies_device(strategies: tuple, packed, r_max: int):
+    """Every strategy's grid solve and the pick among them as one program:
+    (strategy index, r*, U(r*), pocd, cost) device scalars. `packed` holds
+    the JobSpec's leaves in field order, one float32 vector."""
+    job = JobSpec(*(packed[i] for i in range(len(JobSpec._fields))))
+    rs = jnp.arange(r_max, dtype=jnp.float32)
+    best = None
+    for k, s in enumerate(strategies):
+        us = utility(s, rs, job)
+        i = jnp.argmax(us)
+        r = rs[i]
+        sol = (jnp.int32(k), i.astype(jnp.int32), us[i],
+               pocd_of(s, r, job), cost_of(s, r, job))
+        if best is None:
+            best = sol
+        else:
+            # strict: on a tie the earlier strategy keeps its place
+            take = sol[2] > best[2]
+            best = tuple(jnp.where(take, a, b) for a, b in zip(sol, best))
+    return best
+
+
+def solve_strategies(strategies, job: JobSpec, r_max: int) -> Solution:
+    """Best (strategy, r*) over a strategy set on the grid r < r_max, as one
+    compiled program with one input transfer and one device->host read.
+
+    The same answer as solving each strategy with `solve_grid(s, job,
+    r_max)` in turn and keeping the first strictly higher utility, so ties
+    go to the earlier strategy. `strategies=None` means every registered
+    Chronos strategy. `job` is one job with host leaves (Python or NumPy
+    scalars): they are rounded to float32 as `JobSpec.make` rounds them and
+    sent as one vector.
+    """
+    if strategies is None:
+        from ..strategies import names
+        strategies = names(kind="chronos")
+    strategies = tuple(strategies)
+    if not strategies:
+        raise ValueError("solve_strategies needs at least one strategy")
+    with obs_trace.span("optimizer.solve_strategies",
+                        n_strategies=len(strategies), r_max=int(r_max)):
+        out = _solve_strategies_device(
+            strategies, np.asarray(job, np.float32), int(r_max))
+        with obs_trace.span("d2h.wait"):
+            k, r, u, p, c = jax.device_get(out)
+    return Solution(strategies[int(k)], int(r), float(u), float(p), float(c))
+
+
 def solve(job: JobSpec, strategies=None) -> Solution:
     """Best (strategy, r) pair for a job.
 
     `strategies=None` sweeps every registered Chronos strategy
-    (`repro.strategies.names(kind="chronos")`). All per-strategy solves
-    are dispatched before any result is fetched — one transfer each, no
-    sync between dispatches.
+    (`repro.strategies.names(kind="chronos")`). Each strategy is solved on
+    its own certified grid by `solve_grid`, which reads its result before
+    the next strategy is dispatched: one program and one or two reads per
+    strategy. `solve_strategies` solves a set on one shared grid in one.
     """
     if strategies is None:
         from ..strategies import names

@@ -173,28 +173,21 @@ class TailGovernor:
 
     def resolve(self):
         """Force a refit + Algorithm-1 re-solve now."""
-        from ..core import JobSpec, solve_grid
+        from ..core import JobSpec, solve_strategies
         self._since_resolve = 0
         fit = self.registry.refit(self.window_name)
         self.last_fit = fit
         if self.deadline <= fit.t_min * 1.05:
             return self.decision   # deadline below the observed floor
-        spec = JobSpec.make(
+        # host floats: `solve_strategies` sends them as one float32 vector
+        spec = JobSpec(
             t_min=fit.t_min, beta=fit.beta, D=self.deadline, N=self.n_tasks,
             tau_est=self.tau_est_frac * fit.t_min,
             tau_kill=(self.tau_est_frac + self.tau_kill_gap_frac)
             * fit.t_min,
             phi_est=self.phi_est, C=self.price, theta=self.theta,
             R_min=self.r_min)
-        strategies = self.strategies
-        if strategies is None:
-            from ..strategies import names
-            strategies = names(kind="chronos")
-        best = None
-        for s in strategies:
-            sol = solve_grid(s, spec, r_max=self.max_r + 1)
-            if best is None or sol.utility > best.utility:
-                best = sol
+        best = solve_strategies(self.strategies, spec, r_max=self.max_r + 1)
         self.decision = best
         if self.on_resolve is not None:
             self.on_resolve(best, fit)

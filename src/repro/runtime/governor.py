@@ -13,7 +13,7 @@ from typing import Optional
 import numpy as np
 import jax.numpy as jnp
 
-from ..core import (JobSpec, fit_mle, solve_grid, Solution)
+from ..core import (JobSpec, fit_mle, solve_strategies, Solution)
 from .telemetry import Telemetry
 
 
@@ -62,7 +62,7 @@ class StepGovernor:
         if c.deadline <= t_min * 1.05:
             # deadline below the observed floor: speculation cannot help
             return None
-        return JobSpec.make(
+        return JobSpec(
             t_min=t_min, beta=beta, D=c.deadline, N=c.n_tasks,
             tau_est=c.tau_est_frac * t_min,
             tau_kill=(c.tau_est_frac + c.tau_kill_gap_frac) * t_min,
@@ -74,15 +74,8 @@ class StepGovernor:
         if spec is None:
             self.last = Solution("sresume", 0, 0.0, 0.0, 0.0)
             return self.last
-        strategies = self.cfg.strategies
-        if strategies is None:
-            from ..strategies import names
-            strategies = names(kind="chronos")
-        best = None
-        for s in strategies:
-            sol = solve_grid(s, spec, r_max=self.cfg.max_r + 1)
-            if best is None or sol.utility > best.utility:
-                best = sol
+        best = solve_strategies(self.cfg.strategies, spec,
+                                r_max=self.cfg.max_r + 1)
         self.last = best
         return best
 

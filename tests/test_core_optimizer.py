@@ -4,10 +4,11 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core import (JobSpec, solve_grid, solve_algorithm1, solve,
-                        solve_batch, ProgressReport,
+from repro.core import (JobSpec, solve_grid, solve_strategies,
+                        solve_algorithm1, solve, solve_batch, ProgressReport,
                         estimate_completion_chronos, estimate_completion_naive,
                         handoff_offset, fit_mle, sample)
+from repro.strategies import names
 
 CASES = [
     dict(t_min=10, beta=2.0, D=50, N=10, theta=1e-3),
@@ -36,6 +37,51 @@ def test_solve_picks_best_strategy():
     per = {s: solve_grid(s, job).utility for s in ("clone", "srestart", "sresume")}
     assert best.utility == pytest.approx(max(per.values()), abs=1e-6)
     assert best.strategy == max(per, key=per.get)
+
+
+def _governor_spec(t_min, beta, D, N, theta, R_min=0.0):
+    """A JobSpec with host leaves, built as the tail governor builds it."""
+    return JobSpec(t_min=t_min, beta=beta, D=D, N=N, tau_est=0.3 * t_min,
+                   tau_kill=(0.3 + 0.5) * t_min, phi_est=0.25, C=1.0,
+                   theta=theta, R_min=R_min)
+
+
+# request-storm: one leaf request, Pareto(0.709 ms, 1.677), D = 2x the mean
+_STORM = dict(t_min=0.000709, beta=1.677, D=0.00351, N=1, theta=1e-3)
+# its no-hedge PoCD, P(T <= D) = 1 - (t_min / D)^beta
+_STORM_NS = 1.0 - (_STORM["t_min"] / _STORM["D"]) ** _STORM["beta"]
+_CHRONOS = names(kind="chronos")
+
+
+@pytest.mark.parametrize("spec,strategies", [
+    (_STORM, _CHRONOS),
+    # the clone variants tie on a one-task job: the first listed must win
+    (_STORM, ("clone_sjf", "clone", "clone_prop")),
+    (dict(t_min=10.0, beta=1.5, D=40.0, N=200, theta=1e-4), _CHRONOS),
+    (dict(_STORM, R_min=_STORM_NS - 1e-3), _CHRONOS),
+    # an SLA no strategy reaches: every utility is -inf
+    (dict(_STORM, R_min=1.0), _CHRONOS),
+    (dict(t_min=10.0, beta=2.0, D=50.0, N=10, theta=1e-3), ("srestart",)),
+], ids=["storm", "storm-clone-ties", "hadoop-200", "storm-rmin-ns",
+        "unreachable-sla", "one-strategy"])
+def test_solve_strategies_matches_per_strategy_loop(spec, strategies):
+    """One program over a strategy set picks what the per-strategy
+    `solve_grid` loop picks (first strictly best utility, ties to the
+    earlier strategy), from the inputs `JobSpec.make` would round to."""
+    host = _governor_spec(**spec)
+    job = JobSpec.make(**host._asdict())
+    assert (np.asarray(host, np.float32).tobytes()
+            == np.asarray(job, np.float32).tobytes())
+    best = None
+    for s in strategies:
+        sol = solve_grid(s, job, r_max=9)
+        if best is None or sol.utility > best.utility:
+            best = sol
+    got = solve_strategies(strategies, host, r_max=9)
+    assert (got.strategy, got.r_opt) == (best.strategy, best.r_opt)
+    np.testing.assert_allclose([got.utility, got.pocd, got.cost],
+                               [best.utility, best.pocd, best.cost],
+                               rtol=1e-6)
 
 
 def test_solve_batch_matches_scalar():

@@ -154,8 +154,9 @@ def test_profiler_spans_tile_online_serve_epochs(tmp_path):
     spans in the trace's host plane, nested as the loop runs them, and one
     `d2h.wait` per blocking device->host read. Per epoch: 80 hedged + 20
     probe requests = 3 + 1 windows with two reads each, three reads in
-    the combiner, and after the first epoch's refit one solve read and
-    one `solve_grid` read per Chronos strategy at every refit."""
+    the combiner, and after the first epoch's refit one solve read, and at
+    every refit one `solve_strategies` program over all Chronos strategies
+    with one read."""
     from repro.serve import make_requests, serve_trace
     reqs = make_requests("request-storm", n_requests=1000, seed=0)
     with jax.profiler.trace(str(tmp_path)):
@@ -167,13 +168,12 @@ def test_profiler_spans_tile_online_serve_epochs(tmp_path):
     by = {}
     for e in events:
         by.setdefault(e[0], []).append(e)
-    n_chronos = len(names(kind="chronos"))
     assert out.n_refits == 10
     assert {k: len(v) for k, v in by.items()} == {
         "serve.trace": 1, "serve.epoch": 10, "serve.solve": 9,
         "serve.window": 40, "serve.combine": 10, "serve.governor": 10,
-        "optimizer.solve_grid": 10 * n_chronos, "combiner.finalize": 1,
-        "d2h.wait": 171}
+        "optimizer.solve_strategies": 10, "combiner.finalize": 1,
+        "d2h.wait": 131}
     (unit,) = by["serve.trace"]
     epochs = by["serve.epoch"]
     assert all(_within(e, unit) for e in epochs)
@@ -182,14 +182,14 @@ def test_profiler_spans_tile_online_serve_epochs(tmp_path):
     for name in children:
         assert all(any(_within(c, e) for e in epochs) for c in by[name])
     assert all(any(_within(g, c) for c in by["serve.governor"])
-               for g in by["optimizer.solve_grid"])
+               for g in by["optimizer.solve_strategies"])
     inner = [c for name in children for c in by[name]]
     d2h_per_epoch = [sum(_within(w, e) for w in by["d2h.wait"])
                      for e in epochs]
     # every wait inside an epoch sits in one of its four children
     assert sum(d2h_per_epoch) == sum(
         any(_within(w, c) for c in inner) for w in by["d2h.wait"])
-    cold = 4 * 2 + 3 + n_chronos
+    cold = 4 * 2 + 3 + 1
     assert d2h_per_epoch == [cold] + [cold + 1] * 9
     # the two reads after the loop: the utility and the latency summary
     assert len(by["d2h.wait"]) - sum(d2h_per_epoch) == 2

@@ -168,6 +168,36 @@ def test_auto_strategy_follows_governor_decision():
     assert chosen - {"hadoop_ns"}, "governor never picked a hedge"
 
 
+def test_auto_governor_matches_per_strategy_loop(monkeypatch):
+    """The governor's one-program re-solve drives `strategy="auto"` as the
+    per-strategy `solve_grid` loop did: the same epoch strategies, fits
+    and per-request results."""
+    import repro.core as core
+    reqs = make_requests("request-storm", n_requests=1200, seed=6)
+    kw = dict(strategy="auto", window=256, refit_every=200, probe_every=8,
+              min_samples=16)
+    new = serve_trace(KEY, reqs, **kw)
+    calls = []
+
+    def per_strategy_loop(strategies, job, r_max):
+        calls.append(job)
+        spec = core.JobSpec.make(**job._asdict())
+        best = None
+        for s in strategies or names(kind="chronos"):
+            sol = core.solve_grid(s, spec, r_max=r_max)
+            if best is None or sol.utility > best.utility:
+                best = sol
+        return best
+
+    monkeypatch.setattr(core, "solve_strategies", per_strategy_loop)
+    old = serve_trace(KEY, reqs, **kw)
+    assert len(calls) == old.n_refits == new.n_refits > 0
+    assert set(new.epoch_strategies) - {"hadoop_ns"}
+    assert new.epoch_strategies == old.epoch_strategies
+    assert new.fits == old.fits
+    assert _same(new, old)
+
+
 def test_refit_cadence_must_align_with_probes():
     reqs = uniform_requests(64, t_min=1.0, beta=1.5, D=4.0)
     with pytest.raises(ValueError, match="multiple of"):
